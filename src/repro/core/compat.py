@@ -1,52 +1,31 @@
-"""Version-compat shims for JAX APIs that moved between releases.
+"""Thin spellings of JAX mesh and ``shard_map`` APIs used across the repo.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` to ``jax.shard_map``
-and renamed two keywords along the way (``check_rep`` -> ``check_vma``,
-``auto`` -> the complementary ``axis_names``). Callers in this repo use the
-NEW spelling; this wrapper translates for older installs so the same source
-runs on both.
+``shard_map`` passes only the keywords a caller sets, so call sites can
+leave ``check_vma``/``axis_names`` at JAX's defaults; ``axis_names``
+selects the manual axes.
 """
 from __future__ import annotations
 
 from typing import Iterable, Optional
 
-try:                                       # jax >= 0.6: top-level export
-    from jax import shard_map as _shard_map
-    _NEW_API = True
-except ImportError:                        # jax 0.4.x/0.5.x: experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _NEW_API = False
+import jax
+from jax import shard_map as _shard_map
 
 
 def make_auto_mesh(shape, axis_names):
-    """``jax.make_mesh`` with Auto axis types on jax >= 0.6, plain mesh on
-    older versions (where ``jax.sharding.AxisType`` does not exist)."""
-    import jax
-    try:
-        return jax.make_mesh(
-            shape, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    except AttributeError:
-        return jax.make_mesh(shape, axis_names)
+    """``jax.make_mesh`` with every axis of Auto type."""
+    return jax.make_mesh(
+        shape, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: Optional[bool] = None,
               axis_names: Optional[Iterable[str]] = None):
-    """``jax.shard_map`` with the new keyword spelling on any jax version.
-
-    ``axis_names`` selects the manual axes (new API); on the old API it is
-    translated to ``auto`` = the complement of the manual set.
-    """
+    """``jax.shard_map``; ``axis_names`` selects the manual axes."""
     kwargs = {}
-    if _NEW_API:
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-    else:
-        if check_vma is not None:
-            kwargs["check_rep"] = check_vma
-        if axis_names is not None:
-            kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
+    if check_vma is not None:
+        kwargs["check_vma"] = check_vma
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
     return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                       **kwargs)

@@ -49,12 +49,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
     def body(kb, carry):
         acc, m_prev, l_prev = carry
         k_start = kb * block_k
-        # leading dim via a size-1 dslice, not a bare int: older Pallas
-        # interpreters reject scalar indices in load index tuples
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(k_start, block_k),
-                            slice(None)))[0].astype(jnp.float32)  # (bk, hd)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(k_start, block_k),
-                            slice(None)))[0].astype(jnp.float32)
+        k = k_ref[0, pl.ds(k_start, block_k)].astype(jnp.float32)  # (bk, hd)
+        v = v_ref[0, pl.ds(k_start, block_k)].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((2,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         # s: (G, bq, bk) — mask

@@ -62,15 +62,26 @@ def _window9(ext, h, w):
     return [ext[di:di + h, dj:dj + w] for di in range(3) for dj in range(3)]
 
 
+def _to_f32(x):
+    """Widen a tile to float32. Mosaic has no direct cast from 8/16-bit
+    integers (the detector's uint16) to float32; going through int32 is
+    exact for every such value."""
+    if jnp.issubdtype(x.dtype, jnp.integer) and x.dtype.itemsize < 4:
+        x = x.astype(jnp.int32)
+    return x.astype(jnp.float32)
+
+
 def _kernel(ext_ref, dark_ref, mask_ref, count_ref, *, threshold: float,
             tile: int, width: int, height: int):
     """Fused subtract -> median -> Laplacian -> threshold on one row tile.
 
-    ext_ref:  (1, 1, tile+4, width+4) frame tile with 2-px halo all around.
-    dark_ref: (1, tile+4, width+4) matching dark-frame tile.
+    ext_ref:   (1, 1, tile+4, width+4) frame tile with 2-px halo all around.
+    dark_ref:  (1, tile+4, width+4) matching dark-frame tile.
+    count_ref: (1, 1, 8, 128) signal-pixel count of the tile, broadcast over
+               one whole (8, 128) vreg tile so the block is lane-dense.
     """
-    img = ext_ref[0, 0].astype(jnp.float32)
-    dark = dark_ref[0].astype(jnp.float32)
+    img = _to_f32(ext_ref[0, 0])
+    dark = _to_f32(dark_ref[0])
     img = jnp.maximum(img - dark, 0.0)                  # background subtract
     # median on the 1-halo-extended domain: rows/cols [-1, tile+1) x
     # [-1, width+1), from ONE set of 9 shifted neighborhoods
@@ -87,9 +98,11 @@ def _kernel(ext_ref, dark_ref, mask_ref, count_ref, *, threshold: float,
     top = jnp.where(t == 0, med_ext[1:2], med_ext[0:1])
     med_ext = jnp.concatenate([top, med_ext[1:]], axis=0)
     r_star = height - t * tile        # local med_ext index of frame row H-1
-    brow = jax.lax.dynamic_slice(med_ext, (jnp.clip(r_star, 0, tile + 1), 0),
-                                 (1, width + 2))
     ridx = jax.lax.broadcasted_iota(jnp.int32, (tile + 2, 1), 0)
+    # row r_star picked by a masked max over the row iota (Mosaic has no
+    # dynamic sublane slice); exact, since every other row contributes -inf
+    brow = jnp.max(jnp.where(ridx == jnp.clip(r_star, 0, tile + 1), med_ext,
+                             -jnp.inf), axis=0, keepdims=True)
     med_ext = jnp.where(ridx > r_star, brow, med_ext)
     med_ext = jnp.concatenate([med_ext[:, 1:2], med_ext[:, 1:-1],
                                med_ext[:, -2:-1]], axis=1)
@@ -99,7 +112,8 @@ def _kernel(ext_ref, dark_ref, mask_ref, count_ref, *, threshold: float,
     lap = 8.0 * n[4] - (n[0] + n[1] + n[2] + n[3] + n[5] + n[6] + n[7] + n[8])
     mask = (lap > threshold) & (n[4] > threshold * 0.5)
     mask_ref[0] = mask.astype(jnp.uint8)
-    count_ref[0, 0] = jnp.sum(mask.astype(jnp.int32))
+    count_ref[0, 0] = jnp.full((8, 128), jnp.sum(mask.astype(jnp.int32)),
+                               jnp.int32)
 
 
 def _pick_tile(H: int, W: int, vmem_budget_bytes: int) -> int:
@@ -149,7 +163,7 @@ def hedm_reduce(frames: jax.Array, dark: jax.Array, threshold: float = 100.0,
         functools.partial(_kernel, threshold=threshold, tile=tile, width=W,
                           height=H),
         out_shape=(jax.ShapeDtypeStruct((F, Hp, W), jnp.uint8),
-                   jax.ShapeDtypeStruct((F, T), jnp.int32)),
+                   jax.ShapeDtypeStruct((F, T, 8, 128), jnp.int32)),
         grid=(F, T),
         in_specs=[
             pl.BlockSpec((1, 1, tile + 2 * HALO, W + 2 * HALO),
@@ -158,7 +172,7 @@ def hedm_reduce(frames: jax.Array, dark: jax.Array, threshold: float = 100.0,
                          lambda f, t: (t, 0, 0)),
         ],
         out_specs=(pl.BlockSpec((1, tile, W), lambda f, t: (f, t, 0)),
-                   pl.BlockSpec((1, 1), lambda f, t: (f, t))),
+                   pl.BlockSpec((1, 1, 8, 128), lambda f, t: (f, t, 0, 0))),
         interpret=interpret,
     )(ext, dark_ext)
 
@@ -166,5 +180,5 @@ def hedm_reduce(frames: jax.Array, dark: jax.Array, threshold: float = 100.0,
         mask = mask[:, :H]
         counts = jnp.sum(mask.astype(jnp.int32), axis=(1, 2))
     else:
-        counts = jnp.sum(counts, axis=1)
+        counts = jnp.sum(counts[:, :, 0, 0], axis=1)
     return mask, counts

@@ -30,6 +30,17 @@ from repro.train.optimizer import (OptConfig, adamw_update,
                                    clip_by_global_norm, init_opt_state)
 
 
+class _PodLocalCtx(ShardCtx):
+    """Activation constraints inside the pod-manual region. The batch dim is
+    left to the partitioner: constraining it to the data axis there crashes
+    XLA's SPMD partitioner (a device-group check in
+    ExpandDeviceGroupsWithIota). Every ``constrain`` call puts the batch dim
+    first."""
+
+    def constrain(self, x, *spec):
+        return super().constrain(x, P.UNCONSTRAINED, *spec[1:])
+
+
 def _split_microbatches(batch: Dict[str, jax.Array], n_mb: int):
     def split(x):
         return jnp.moveaxis(
@@ -85,9 +96,9 @@ def make_train_step(cfg: ModelConfig, shape: ShapeConfig, opt: OptConfig,
         return train_step
 
     mesh = ctx.mesh
-    inner_ctx = ShardCtx(mesh=mesh, dp_axes=("data",),
-                         fsdp_axis=ctx.fsdp_axis, tp_axis=ctx.tp_axis,
-                         sequence_parallel=ctx.sequence_parallel)
+    inner_ctx = _PodLocalCtx(mesh=mesh, dp_axes=("data",),
+                             fsdp_axis=ctx.fsdp_axis, tp_axis=ctx.tp_axis,
+                             sequence_parallel=ctx.sequence_parallel)
 
     def train_step(params, opt_state, batch):
         def pod_body(params, opt_state, batch):

@@ -1,0 +1,76 @@
+"""Compile the main path's device programs for a described TPU v5e.
+
+Nothing runs: the TPU compiler, installed with JAX, compiles for a chip that
+is described and not attached, and refuses what the chip would refuse (a
+block shape off the (8, 128) tiling, a primitive Mosaic cannot lower, too
+much VMEM). The topology is described inside a fixture, never at import,
+so that only the test worker given this file loads the TPU library.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.hedm.pipeline import N_GVEC, fit_grid
+from repro.kernels.hedm_reduce import hedm_reduce
+
+FRAME = 2048
+FIT_POINTS = 4109
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep the cache off meanwhile
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")   # no compiler logs under /tmp
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 - any failure: no compiler
+            jax.config.update("jax_enable_compilation_cache", was_on)
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile_kernel(one_chip, shape, dtype):
+    F, H, W = shape
+    x = jax.ShapeDtypeStruct((F, H, W), dtype, sharding=one_chip)
+    d = jax.ShapeDtypeStruct((H, W), dtype, sharding=one_chip)
+    # interpret=False: off the chip the kernel would pick the interpreter
+    fn = functools.partial(hedm_reduce, threshold=200.0, interpret=False)
+    return jax.jit(fn).lower(x, d).compile()
+
+
+@pytest.mark.parametrize("dtype", ["uint16", "float32"])
+def test_hedm_reduce_compiles_for_v5e_at_detector_size(one_chip, dtype):
+    compiled = _compile_kernel(one_chip, (8, FRAME, FRAME), jnp.dtype(dtype))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_hedm_reduce_compiles_for_v5e_with_partial_last_tile(one_chip):
+    """2000 rows over the default 64-row tile: the last tile is partial."""
+    compiled = _compile_kernel(one_chip, (8, 2000, FRAME), jnp.uint16)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fit_grid_compiles_for_v5e_at_paper_job_count(one_chip):
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in ((FIT_POINTS, 2 * N_GVEC), (N_GVEC, 3), (FIT_POINTS, 3))]
+    compiled = jax.jit(fit_grid).lower(*args).compile()
+    assert compiled.memory_analysis() is not None

@@ -79,18 +79,6 @@ def test_sharded_train_step_runs_and_matches_single_device():
     assert "OK" in out
 
 
-def _partial_manual_shard_map_supported() -> bool:
-    """Partial-manual shard_map (manual 'pod', auto data/model) crashes XLA's
-    SPMD partitioner on jax 0.4.x (Check failed: sharding.IsManualSubgroup());
-    it needs the jax>=0.6 axis_names API generation."""
-    sys.path.insert(0, os.path.join(ROOT, "src"))
-    from repro.core.compat import _NEW_API
-    return _NEW_API
-
-
-@pytest.mark.skipif(not _partial_manual_shard_map_supported(),
-                    reason="partial-manual shard_map unsupported by this "
-                           "jax/XLA (crashes the SPMD partitioner)")
 def test_compressed_dcn_train_step_on_pod_mesh():
     out = run_with_devices(textwrap.dedent("""
         import jax, jax.numpy as jnp
