@@ -30,7 +30,8 @@ from __future__ import annotations
 
 import time as _time
 from dataclasses import dataclass
-from typing import Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import (Dict, Iterator, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 
@@ -86,24 +87,34 @@ def stream_to_fs(fabric: Fabric, frames: np.ndarray, prefix: str = "scan"
 # stage 1: reduction
 # ---------------------------------------------------------------------------
 
-def label_components(mask: np.ndarray) -> Tuple[np.ndarray, int]:
-    """Vectorized 4-connected component labeling (run-based two-pass).
+class Runs(NamedTuple):
+    """Horizontal runs of a mask, in row-major order, with their final
+    component labels: run ``i`` covers columns ``[col_s[i], col_e[i])`` of
+    row ``row[i]`` and belongs to component ``label[i]`` (1..``n``)."""
+    row: np.ndarray
+    col_s: np.ndarray
+    col_e: np.ndarray
+    label: np.ndarray              # int32
+    n: int                         # number of components
 
-    Pass 1 finds horizontal runs of the whole mask at once (a sentinel
-    column keeps runs from spanning rows) and unions runs that overlap
-    between adjacent rows; pass 2 paints final labels with one scatter.
-    Work is O(H*W) vectorized + O(#runs) scalar — for sparse diffraction
-    masks #runs is ~100x smaller than #pixels, which is what makes stage-1
-    labeling faster than the filter kernel it post-processes.
+
+def label_runs(mask: np.ndarray) -> Runs:
+    """Vectorized 4-connected component labeling of a mask's runs.
+
+    Pass 1a finds horizontal runs of the whole mask at once (a sentinel
+    column keeps runs from spanning rows); pass 1b unions runs that
+    overlap between adjacent rows; the roots are then renumbered in scan
+    order. Work is O(H*W) vectorized + O(#runs) scalar — for sparse
+    diffraction masks #runs is ~100x smaller than #pixels.
 
     Label numbering matches ``_union_find_label`` exactly (components
-    numbered by first pixel in row-major scan order), so the two are
-    interchangeable; tests assert equivalence.
+    numbered by first pixel in row-major scan order).
     """
     H, W = mask.shape
     m = np.ascontiguousarray(mask, dtype=bool)
     if not m.any():
-        return np.zeros((H, W), np.int32), 0
+        none = np.zeros(0, np.int64)
+        return Runs(none, none, none, np.zeros(0, np.int32), 0)
 
     # --- pass 1a: horizontal runs over the flattened mask -----------------
     padded = np.zeros((H, W + 1), bool)          # sentinel column: runs
@@ -157,15 +168,54 @@ def label_components(mask: np.ndarray) -> Tuple[np.ndarray, int]:
             break
         parent = p2
 
-    # --- pass 2: renumber roots in scan order, paint runs -----------------
+    # --- renumber roots in scan order -------------------------------------
     roots = np.unique(parent)                # sorted == first-run order
     run_label = (np.searchsorted(roots, parent) + 1).astype(np.int32)
-    lengths = ends - starts
-    pos = (np.arange(lengths.sum()) + np.repeat(
-        starts - np.concatenate(([0], lengths.cumsum()[:-1])), lengths))
-    out = np.zeros(H * (W + 1), np.int32)
-    out[pos] = np.repeat(run_label, lengths)
-    return out.reshape(H, W + 1)[:, :W], len(roots)
+    return Runs(rows, col_s, col_e, run_label, len(roots))
+
+
+def run_pixels(runs: Runs) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Every pixel of ``runs`` as ``(y, x, label)``, in row-major order."""
+    lengths = runs.col_e - runs.col_s
+    first = np.concatenate(([0], lengths.cumsum()[:-1]))
+    x = np.arange(lengths.sum()) + np.repeat(runs.col_s - first, lengths)
+    return (np.repeat(runs.row, lengths), x,
+            np.repeat(runs.label, lengths))
+
+
+def run_centroids(runs: Runs, frame: np.ndarray) -> np.ndarray:
+    """``(n, 3)`` float32 peak rows of each component of ``runs``:
+    intensity-weighted centroid y, x and summed intensity of ``frame``,
+    accumulated in float64.
+
+    One bincount pass per moment over the runs' pixels only. The pixels
+    come in row-major order, as a scan of a painted label image gives
+    them, so the float64 sums are the ones that scan would give."""
+    n = runs.n
+    y, x, lab = run_pixels(runs)
+    v = frame[y, x].astype(np.float64)
+    s_i = np.bincount(lab, weights=v, minlength=n + 1)
+    s_y = np.bincount(lab, weights=v * y, minlength=n + 1)
+    s_x = np.bincount(lab, weights=v * x, minlength=n + 1)
+    denom = np.maximum(s_i, 1e-9)
+    return np.stack([s_y / denom, s_x / denom, s_i],
+                    axis=1)[1:].astype(np.float32)
+
+
+def label_components(mask: np.ndarray) -> Tuple[np.ndarray, int]:
+    """4-connected component labels of ``mask`` as an ``(H, W)`` int32
+    image and the component count: :func:`label_runs`, then one scatter
+    paints each run's label.
+
+    Label numbering matches ``_union_find_label`` exactly (components
+    numbered by first pixel in row-major scan order), so the two are
+    interchangeable; tests assert equivalence.
+    """
+    runs = label_runs(mask)
+    y, x, lab = run_pixels(runs)
+    out = np.zeros(mask.shape, np.int32)
+    out[y, x] = lab
+    return out, runs.n
 
 
 def _union_find_label(mask: np.ndarray) -> Tuple[np.ndarray, int]:
@@ -230,7 +280,10 @@ def reduce_frames(frames: np.ndarray, dark: np.ndarray,
     (free while no profiler trace is recording). The copy in and the filter
     wait for the device before their span ends, so that each span holds its
     own work, and the copies' spans carry the bytes they moved as the stat
-    ``bytes``."""
+    ``bytes``. Each frame's ``hedm.label`` span carries the runs the labeler
+    found (stat ``runs``) and its ``hedm.centroids`` span the signal pixels
+    whose moments it summed (stat ``pixels``): both follow the signal, not
+    the frame size."""
     with TraceAnnotation("hedm.to_device") as span:
         frames_d, dark_d = jnp.asarray(frames), jnp.asarray(dark)
         jax.block_until_ready((frames_d, dark_d))
@@ -248,26 +301,20 @@ def reduce_frames(frames: np.ndarray, dark: np.ndarray,
         masks = np.asarray(masks)
         counts = np.asarray(counts)
         span.set_metadata(bytes=masks.nbytes + counts.nbytes)
-    H, W = frames.shape[1:]
+    # The pixels' (y, x) come from the labeler's runs, so this span holds no
+    # work; it stays, once a call, because benchmarks/chip's centroid reader
+    # and its tests still count it.
     with TraceAnnotation("hedm.index_grid"):
-        yy, xx = np.divmod(np.arange(H * W), W)
+        pass
     out = []
     for f in range(frames.shape[0]):
-        with TraceAnnotation("hedm.label"):
-            labels, n = label_components(masks[f] > 0)
-        # intensity-weighted centroids: one bincount pass per moment instead
-        # of a per-label nonzero scan over the full frame
-        with TraceAnnotation("hedm.centroids"):
-            lab = labels.ravel()
-            sel = np.flatnonzero(lab)
-            l_s, v_s = lab[sel], frames[f].ravel()[sel].astype(np.float64)
-            s_i = np.bincount(l_s, weights=v_s, minlength=n + 1)
-            s_y = np.bincount(l_s, weights=v_s * yy[sel], minlength=n + 1)
-            s_x = np.bincount(l_s, weights=v_s * xx[sel], minlength=n + 1)
-            denom = np.maximum(s_i, 1e-9)
-            peaks = np.stack([s_y / denom, s_x / denom, s_i],
-                             axis=1)[1:].astype(np.float32)
-        out.append(ReducedFrame(f, int(counts[f]), n, peaks))
+        with TraceAnnotation("hedm.label") as span:
+            runs = label_runs(masks[f] > 0)
+            span.set_metadata(runs=len(runs.row))
+        with TraceAnnotation("hedm.centroids") as span:
+            peaks = run_centroids(runs, frames[f])
+            span.set_metadata(pixels=int(np.sum(runs.col_e - runs.col_s)))
+        out.append(ReducedFrame(f, int(counts[f]), runs.n, peaks))
     return out
 
 

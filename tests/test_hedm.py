@@ -1,13 +1,15 @@
 """HEDM application: stage-1 reduction and stage-2 orientation fitting."""
 import numpy as np
+import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.hedm.pipeline import (fit_grid, label_components, make_gvectors,
-                                 reduce_frames, simulate_detector_frames,
-                                 stream_to_fs, synth_grid_observations,
-                                 _union_find_label)
+from repro.hedm.pipeline import (fit_grid, label_components, label_runs,
+                                 make_gvectors, reduce_frames, run_centroids,
+                                 simulate_detector_frames, stream_to_fs,
+                                 synth_grid_observations, _union_find_label)
 from repro.core.fabric import Fabric
+from repro.kernels.hedm_reduce_ref import reference
 
 
 def test_stage1_detects_spots():
@@ -90,6 +92,102 @@ def test_bincount_centroids_match_per_label_scan():
             np.testing.assert_allclose(
                 r.peaks[lbl - 1],
                 [(ys * w).sum(), (xs * w).sum(), inten.sum()], rtol=1e-4)
+
+
+def _dense_peaks(labels, n, frame):
+    """Centroids from a painted label image: a ``flatnonzero`` scan of the
+    whole image, a ``divmod`` index grid and one bincount a moment. The
+    reference the run-based centroids must equal bit for bit."""
+    H, W = labels.shape
+    yy, xx = np.divmod(np.arange(H * W), W)
+    lab = labels.ravel()
+    sel = np.flatnonzero(lab)
+    l_s, v_s = lab[sel], frame.ravel()[sel].astype(np.float64)
+    s_i = np.bincount(l_s, weights=v_s, minlength=n + 1)
+    s_y = np.bincount(l_s, weights=v_s * yy[sel], minlength=n + 1)
+    s_x = np.bincount(l_s, weights=v_s * xx[sel], minlength=n + 1)
+    denom = np.maximum(s_i, 1e-9)
+    return np.stack([s_y / denom, s_x / denom, s_i],
+                    axis=1)[1:].astype(np.float32)
+
+
+def _mask(case):
+    if case == "empty":
+        return np.zeros((6, 6), bool)
+    if case == "full":
+        return np.ones((5, 9), bool)
+    if case == "edge_columns":              # runs touch column 0 and W-1
+        m = np.zeros((7, 10), bool)
+        m[:, 0] = m[:, -1] = m[3, :] = True
+        m[5, 6:] = m[1, :2] = True
+        return m
+    if case == "corner":                    # one set pixel at (0, 0)
+        m = np.zeros((4, 4), bool)
+        m[0, 0] = True
+        return m
+    if case == "single_pixels":
+        m = np.zeros((9, 11), bool)
+        m[::2, ::2] = True
+        return m
+    if case == "snake":
+        m = np.zeros((5, 5), bool)
+        m[0, :] = m[2, :] = m[4, :] = True
+        m[1, 0] = m[3, 4] = True
+        return m
+    rng = np.random.default_rng(int(case.split("_")[1]))
+    H, W = (int(v) for v in rng.integers(1, 48, 2))
+    return rng.random((H, W)) < rng.uniform(0.05, 0.8)
+
+
+MASKS = ["empty", "full", "edge_columns", "corner", "single_pixels",
+         "snake"] + [f"random_{i}" for i in range(6)]
+
+
+@pytest.mark.parametrize("case", MASKS)
+def test_label_components_is_the_painting_of_label_runs(case):
+    mask = _mask(case)
+    runs = label_runs(mask)
+    painted = np.zeros(mask.shape, np.int32)
+    for r, c0, c1, lbl in zip(runs.row, runs.col_s, runs.col_e, runs.label):
+        painted[r, c0:c1] = lbl
+    labels, n = label_components(mask)
+    assert n == runs.n
+    assert labels.dtype == np.int32 and np.array_equal(labels, painted)
+    assert np.array_equal(painted > 0, mask)
+    key = runs.row * (mask.shape[1] + 1) + runs.col_s
+    assert np.all(np.diff(key) > 0)         # row-major order
+
+
+@pytest.mark.parametrize("dtype", [np.uint16, np.float32])
+@pytest.mark.parametrize("case", MASKS)
+def test_run_centroids_equal_the_dense_reference(case, dtype):
+    mask = _mask(case)
+    rng = np.random.default_rng(len(case))
+    frame = (rng.integers(0, 2**16, mask.shape).astype(dtype)
+             if dtype == np.uint16 else
+             rng.uniform(0, 4000, mask.shape).astype(dtype))
+    labels, n = _union_find_label(mask)
+    got = run_centroids(label_runs(mask), frame)
+    want = _dense_peaks(labels, n, frame)
+    assert got.dtype == want.dtype == np.float32
+    assert got.shape == want.shape == (n, 3)
+    assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("dtype", [np.uint16, np.float32])
+def test_reduce_frames_peaks_equal_the_dense_reference(dtype):
+    """Detector frames as the chip path takes them (uint16) and as the
+    staged paths hand them over (float32)."""
+    frames, dark = simulate_detector_frames(3, size=96, n_spots=6, seed=11)
+    frames, dark = frames.astype(dtype), dark.astype(dtype)
+    red = reduce_frames(frames, dark, threshold=200.0, use_kernel=False)
+    masks, _ = reference(jnp.asarray(frames), jnp.asarray(dark),
+                         threshold=200.0)
+    assert sum(r.n_spots for r in red) > 0
+    for r, frame, mask in zip(red, frames, np.asarray(masks)):
+        labels, n = _union_find_label(mask > 0)
+        assert r.n_spots == n
+        assert np.array_equal(r.peaks, _dense_peaks(labels, n, frame))
 
 
 def test_detector_sim_spots_are_gaussian_and_bright():

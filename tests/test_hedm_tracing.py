@@ -2,18 +2,21 @@
 
 ``reduce_frames`` marks its copies, filter, labeling, index grid and
 centroids with ``hedm.*`` spans on the JAX profiler's host plane, and its
-copies' spans carry the bytes they moved as the stat ``bytes``. The spans
-cost nothing while no trace records, and outputs are the same with a trace
-recording or not.
+copies' spans carry the bytes they moved as the stat ``bytes``; each frame's
+labeling and centroid spans carry the runs and signal pixels they handled
+(stats ``runs`` and ``pixels``). The spans cost nothing while no trace
+records, and outputs are the same with a trace recording or not.
 """
 import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 from jax.profiler import ProfileData, TraceAnnotation
 
 from repro.hedm.pipeline import (reduce_frames, reduce_frames_online,
-                                 simulate_detector_frames)
+                                 simulate_detector_frames, _union_find_label)
+from repro.kernels.hedm_reduce_ref import reference
 
 SPANS = ("hedm.to_device", "hedm.filter", "hedm.from_device",
          "hedm.index_grid", "hedm.label", "hedm.centroids")
@@ -116,3 +119,24 @@ def test_online_reduction_has_one_set_of_spans_a_window(scan, tmp_path):
     assert names.count("hedm.label") == names.count("hedm.centroids") == F
     assert sum(st["bytes"] for n, _, _, st in events
                if n == "hedm.to_device") == (F + 2) * PIXELS * 2
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_label_and_centroid_spans_count_runs_and_pixels(scan, tmp_path,
+                                                        use_kernel):
+    frames, dark = scan
+    _, events = _traced(
+        tmp_path, lambda: reduce_frames(frames, dark, use_kernel=use_kernel))
+    masks, _ = reference(jnp.asarray(frames), jnp.asarray(dark),
+                         threshold=200.0)
+    dense = [_union_find_label(m > 0)[0] > 0 for m in np.asarray(masks)]
+    # a run starts at a labelled pixel in column 0 or after an unlabelled one
+    runs = [int(np.count_nonzero(d & ~np.pad(d, ((0, 0), (1, 0)))[:, :-1]))
+            for d in dense]
+    pixels = [int(np.count_nonzero(d)) for d in dense]
+    stats = {name: [st for n, _, _, st in events if n == name]
+             for name in SPANS}
+    assert sum(pixels) > 0
+    assert [st.get("runs") for st in stats["hedm.label"]] == runs
+    assert [st.get("pixels") for st in stats["hedm.centroids"]] == pixels
+    assert len(stats["hedm.index_grid"]) == 1
