@@ -22,13 +22,15 @@ real memory traffic of the simulator goes away.
 
 Device-level (``device_replicate`` / ``device_shard``): the same algorithm
 expressed on the JAX mesh with shard_map + lax.all_gather. Each process
-contributes its 1/P shard; the all-gather rides ICI. Used by checkpoint
-restore and the input pipeline; testable on CPU fake devices.
+contributes its 1/P shard; the all-gather rides ICI. Used by
+``staged_restore`` and by the HEDM scan's staging into HBM
+(``repro.hedm.pipeline.stage_scan``); testable on CPU fake devices.
 
 All modes byte-exact: tests assert staged replicas equal the source.
 """
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -36,9 +38,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.compat import shard_map
 from repro.core.compression import CompressionLike, CompressionStats
 from repro.core.fabric import Fabric
 from repro.core.topology import TopologyLike
@@ -720,16 +722,34 @@ def device_replicate(mesh: Mesh, x: jax.Array, axis: str = "data"
     Input: x sharded P(axis) on its leading dim. Output: fully replicated.
     This is the staging all-gather: read-shards once, replicate over ICI —
     instead of every participant fetching the full buffer from storage.
+    The jitted all-gather is built once per (mesh, axis), so a second
+    call with the same shapes compiles nothing.
     """
-    spec_in = P(axis)
-    spec_out = P()
+    return _replicator(mesh, axis)(x)
 
-    def body(shard):
-        return jax.lax.all_gather(shard, axis, tiled=True)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(spec_in,), out_specs=spec_out,
-                   check_vma=False)
-    return jax.jit(fn)(x)
+GATHER_PIECE_BYTES = 256 << 20     # one all-gather's output, per chip
+
+
+@functools.lru_cache(maxsize=16)
+def _replicator(mesh: Mesh, axis: str):
+    chips = mesh.shape[axis]
+
+    def staging_all_gather(shard):
+        # Gathered a few rows at a time into the replica: gathered whole,
+        # the TPU's all-gather lands in a temporary as large as the replica
+        # and is then copied, which needs twice its HBM.
+        row_bytes = shard.dtype.itemsize * math.prod(shard.shape[1:])
+        rows = max(1, GATHER_PIECE_BYTES // max(1, chips * row_bytes))
+        out = jnp.zeros((chips,) + shard.shape, shard.dtype)
+        for r0 in range(0, shard.shape[0], rows):
+            piece = jax.lax.all_gather(shard[r0:r0 + rows], axis)
+            out = jax.lax.dynamic_update_slice_in_dim(out, piece, r0, axis=1)
+        return out.reshape((chips * shard.shape[0],) + shard.shape[1:])
+
+    return jax.jit(jax.shard_map(staging_all_gather, mesh=mesh,
+                                 in_specs=(P(axis),),
+                                 out_specs=P(), check_vma=False))
 
 
 def device_shard(mesh: Mesh, x: jax.Array, spec: P) -> jax.Array:

@@ -19,6 +19,12 @@ incrementally per sliding window over a streamed acquisition
 (`repro.core.streaming`): results are produced while the detector is still
 writing, and are bit-identical to the batch path (``run_batch_hedm``).
 
+Resident mode — ``stage_scan`` stages a scan into the HBM of every chip of
+a mesh as the paper stages a dataset into node memory (a share of the
+frames from the host to each chip, then an all-gather), and
+``reduce_resident`` runs stage 1 from those replicas, each chip filtering
+its own share; the peaks equal ``reduce_frames_online``'s.
+
 Interactive mode — ``run_interactive_hedm`` drives N concurrent analysis
 sessions over M scans through the long-lived dataset catalog + staging
 service (`repro.core.datasvc`): sessions lease datasets (coalescing
@@ -28,6 +34,7 @@ results back to the shared FS with the collective ``stage_out`` — the
 """
 from __future__ import annotations
 
+import functools
 import time as _time
 from dataclasses import dataclass
 from typing import (Dict, Iterator, List, NamedTuple, Optional, Sequence,
@@ -38,8 +45,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.profiler import TraceAnnotation
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.fabric import Fabric
+from repro.core.staging import device_replicate, device_shard
 
 
 # ---------------------------------------------------------------------------
@@ -306,16 +315,22 @@ def reduce_frames(frames: np.ndarray, dark: np.ndarray,
     # and its tests still count it.
     with TraceAnnotation("hedm.index_grid"):
         pass
-    out = []
-    for f in range(frames.shape[0]):
-        with TraceAnnotation("hedm.label") as span:
-            runs = label_runs(masks[f] > 0)
-            span.set_metadata(runs=len(runs.row))
-        with TraceAnnotation("hedm.centroids") as span:
-            peaks = run_centroids(runs, frames[f])
-            span.set_metadata(pixels=int(np.sum(runs.col_e - runs.col_s)))
-        out.append(ReducedFrame(f, int(counts[f]), runs.n, peaks))
-    return out
+    return [_peak_list(f, masks[f], counts[f], frames[f])
+            for f in range(frames.shape[0])]
+
+
+def _peak_list(frame_id: int, mask: np.ndarray, count, frame: np.ndarray
+               ) -> ReducedFrame:
+    """Host half of stage 1 for one frame: label the mask's runs (span
+    ``hedm.label``, stat ``runs``) and take each component's centroid
+    from the raw frame (span ``hedm.centroids``, stat ``pixels``)."""
+    with TraceAnnotation("hedm.label") as span:
+        runs = label_runs(mask > 0)
+        span.set_metadata(runs=len(runs.row))
+    with TraceAnnotation("hedm.centroids") as span:
+        peaks = run_centroids(runs, frame)
+        span.set_metadata(pixels=int(np.sum(runs.col_e - runs.col_s)))
+    return ReducedFrame(frame_id, int(count), runs.n, peaks)
 
 
 # ---------------------------------------------------------------------------
@@ -341,6 +356,103 @@ def reduce_frames_online(frames: np.ndarray, dark: np.ndarray,
         for r in chunk:
             r.frame_id += w0
         yield chunk
+
+
+# ---------------------------------------------------------------------------
+# stage 1 over a scan resident in HBM (staged once, reduced by every chip)
+# ---------------------------------------------------------------------------
+
+class ResidentScan(NamedTuple):
+    """A scan staged into device memory: every chip of the mesh holds a
+    replica of all frames and of the dark frame. ``host`` is the scan as
+    the host holds it, which the centroids read."""
+    frames: jax.Array              # (F, H, W), replicated over the mesh
+    dark: jax.Array                # (H, W), replicated over the mesh
+    host: np.ndarray               # (F, H, W)
+    mesh: Mesh
+    axis: str
+
+
+def stage_scan(mesh: Mesh, scan: np.ndarray, dark: np.ndarray,
+               axis: str = "data") -> ResidentScan:
+    """Stage ``scan`` into the HBM of every chip along ``axis`` of
+    ``mesh``, as the paper stages a dataset into node memory: each chip
+    is sent 1/P of the frames from the host, then an all-gather over the
+    interconnect leaves the whole scan on every chip.
+
+    Span ``hedm.to_devices`` holds the copy from the host (the stripes,
+    and the dark frame to each chip; stats ``bytes``, the host bytes sent,
+    and ``chips``); span ``hedm.all_gather`` holds the gather (stat
+    ``bytes``, what the chips received over the interconnect, summed).
+    Both end when the arrays are on the chips. The frame count must be a
+    multiple of the chip count."""
+    chips = mesh.shape[axis]
+    if len(scan) % chips:
+        raise ValueError(f"a scan of {len(scan)} frames does not split "
+                         f"evenly over {chips} chips")
+    with TraceAnnotation("hedm.to_devices") as span:
+        stripes = device_shard(mesh, scan, P(axis))
+        dark_d = device_shard(mesh, dark, P())
+        jax.block_until_ready((stripes, dark_d))
+        span.set_metadata(bytes=scan.nbytes + chips * dark.nbytes,
+                          chips=chips)
+    with TraceAnnotation("hedm.all_gather") as span:
+        frames = device_replicate(mesh, stripes, axis)
+        jax.block_until_ready(frames)
+        span.set_metadata(bytes=(chips - 1) * scan.nbytes)
+    return ResidentScan(frames, dark_d, scan, mesh, axis)
+
+
+def reduce_resident(resident: ResidentScan, window: int = 16,
+                    threshold: float = 200.0
+                    ) -> Iterator[List[ReducedFrame]]:
+    """Stage 1 over a resident scan: yield one ``ReducedFrame`` list a
+    step. Chip ``i`` owns frames ``[i*F/P, (i+1)*F/P)``; at each step every
+    chip filters the next ``window`` frames of its own share, read from
+    its replica, in one call over the mesh (the offset is an argument, so
+    the steps share one compiled program; a ragged last step is the only
+    other). Frame ids are global, and every frame comes back exactly once.
+    Masks are the kernel's and peaks come from the host copy, as in
+    :func:`reduce_frames`, under the same ``hedm.filter``, ``hedm.label``
+    and ``hedm.centroids`` spans; the copy back is span
+    ``hedm.from_devices`` (stats ``bytes`` and ``chips``)."""
+    chips = resident.mesh.shape[resident.axis]
+    share = len(resident.host) // chips
+    for first in range(0, share, window):
+        width = min(window, share - first)
+        step = _own_frames_filter(resident.mesh, resident.axis, width,
+                                  float(threshold))
+        with TraceAnnotation("hedm.filter"):
+            masks, counts = step(resident.frames, resident.dark,
+                                 np.int32(first))
+            jax.block_until_ready((masks, counts))
+        with TraceAnnotation("hedm.from_devices") as span:
+            masks = np.asarray(masks)
+            counts = np.asarray(counts)
+            span.set_metadata(bytes=masks.nbytes + counts.nbytes,
+                              chips=chips)
+        ids = [i * share + first + j for i in range(chips)
+               for j in range(width)]
+        yield [_peak_list(f, masks[k], counts[k], resident.host[f])
+               for k, f in enumerate(ids)]
+
+
+@functools.lru_cache(maxsize=16)
+def _own_frames_filter(mesh: Mesh, axis: str, window: int, threshold: float):
+    """The jitted step of :func:`reduce_resident`: on every chip, frames
+    ``[i*F/P + first, ... + window)`` of its replica through
+    ``hedm_reduce``; masks and counts come out stacked chip by chip."""
+    from repro.kernels.ops import hedm_reduce
+    chips = mesh.shape[axis]
+
+    def filter_own_frames(frames, dark, first):
+        start = jax.lax.axis_index(axis) * (frames.shape[0] // chips) + first
+        part = jax.lax.dynamic_slice_in_dim(frames, start, window)
+        return hedm_reduce(part, dark, threshold=threshold)
+
+    return jax.jit(jax.shard_map(
+        filter_own_frames, mesh=mesh, in_specs=(P(), P(), P()),
+        out_specs=(P(axis), P(axis)), check_vma=False))
 
 
 @dataclass

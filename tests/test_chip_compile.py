@@ -10,14 +10,19 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
+from repro.core.staging import device_replicate
+from repro.hedm import pipeline
 from repro.hedm.pipeline import N_GVEC, fit_grid
 from repro.kernels.hedm_reduce import hedm_reduce
 
 FRAME = 2048
 FIT_POINTS = 4109
+SCAN_FRAMES = 736           # the NF-HEDM scan, 184 frames a chip on four
 
 
 @pytest.fixture(scope="module")
@@ -74,3 +79,45 @@ def test_fit_grid_compiles_for_v5e_at_paper_job_count(one_chip):
             for s in ((FIT_POINTS, 2 * N_GVEC), (N_GVEC, 3), (FIT_POINTS, 3))]
     compiled = jax.jit(fit_grid).lower(*args).compile()
     assert compiled.memory_analysis() is not None
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    return Mesh(np.array(topo.devices), ("data",))
+
+
+def test_scan_all_gather_compiles_for_v5e_2x2_without_a_second_replica(
+        four_chips):
+    """Staging the 6.17-GB scan: 1/4 in, the whole scan out on every chip,
+    and temporaries far below a second replica."""
+    shape = (SCAN_FRAMES, FRAME, FRAME)
+    x = jax.ShapeDtypeStruct(shape, jnp.uint16,
+                             sharding=NamedSharding(four_chips, P("data")))
+    compiled = jax.jit(lambda a: device_replicate(four_chips, a)).lower(
+        x).compile()
+    mem = compiled.memory_analysis()
+    replica = int(np.prod(shape)) * 2
+    assert "all-gather" in compiled.as_text()
+    assert mem.argument_size_in_bytes == replica // 4
+    assert mem.output_size_in_bytes == replica
+    assert mem.temp_size_in_bytes < replica // 4
+
+
+@pytest.mark.parametrize("window", [16, 8])
+def test_resident_step_compiles_for_v5e_2x2_at_detector_size(
+        four_chips, monkeypatch, window):
+    """One step of ``reduce_resident`` over the scan's replica: the 16-frame
+    step and the ragged 8-frame tail of a 184-frame share."""
+    # off the chip the kernel would pick the interpreter
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    whole = NamedSharding(four_chips, P())
+    args = (jax.ShapeDtypeStruct((SCAN_FRAMES, FRAME, FRAME), jnp.uint16,
+                                 sharding=whole),
+            jax.ShapeDtypeStruct((FRAME, FRAME), jnp.uint16, sharding=whole),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=whole))
+    step = pipeline._own_frames_filter(four_chips, "data", window, 200.0)
+    compiled = step.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # each chip returns its own window's uint8 masks (and padded counts)
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert out // (FRAME * FRAME) == window
