@@ -107,8 +107,15 @@ class Runs(NamedTuple):
     n: int                         # number of components
 
 
-def label_runs(mask: np.ndarray) -> Runs:
+def label_runs(mask: np.ndarray, rows: Optional[np.ndarray] = None
+               ) -> Runs:
     """Vectorized 4-connected component labeling of a mask's runs.
+
+    ``mask`` is a whole frame, or, with ``rows``, only some of its rows:
+    row ``i`` of ``mask`` is row ``rows[i]`` of the frame (increasing),
+    and every row left out is empty. Runs carry frame rows either way, and
+    two rows join only where they are adjacent in the frame, so a frame's
+    occupied rows label exactly as the whole frame does.
 
     Pass 1a finds horizontal runs of the whole mask at once (a sentinel
     column keeps runs from spanning rows); pass 1b unions runs that
@@ -134,9 +141,10 @@ def label_runs(mask: np.ndarray) -> Runs:
     ends = np.flatnonzero(d == -1) + 1           # every run closes (sentinel)
     if flat[0]:
         starts = np.concatenate(([0], starts))
-    rows = starts // (W + 1)
-    col_s = starts - rows * (W + 1)
-    col_e = ends - rows * (W + 1)
+    local = starts // (W + 1)
+    col_s = starts - local * (W + 1)
+    col_e = ends - local * (W + 1)
+    rows = local if rows is None else np.asarray(rows, np.int64)[local]
     n_runs = len(starts)
 
     # --- pass 1b: union runs that overlap between adjacent rows ----------
@@ -280,6 +288,40 @@ class ReducedFrame:
     peaks: np.ndarray              # (n_spots, 3): y, x, intensity
 
 
+@jax.jit
+def mask_rows(masks: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The occupied rows of each of ``(F, H, W)`` masks, bit-packed: what
+    of a stage-1 mask leaves the chip.
+
+    Returns ``rows`` ``(F, R)`` int32, the indices of each frame's first
+    ``R = H // 8`` rows that hold any signal, in increasing order (slots
+    past a frame's count hold ``H - 1``);
+    ``n_rows`` ``(F,)`` int32, how many rows of the frame hold signal,
+    which may exceed ``R``; and ``packed`` ``(F, R, ceil(W / 8))`` uint8,
+    those rows 8 pixels a byte, little-endian
+    (``np.unpackbits(packed, axis=-1, count=W, bitorder="little")``).
+    ``R`` bounds the payload at 1/64 of the dense masks' bytes; a frame
+    with more occupied rows than ``R`` has to come back whole.
+    Row occupancy is one reduction over each row and the rows are chosen
+    on the ``(F, H)`` occupancy alone, so nothing scatters, sorts or
+    searches over the pixels."""
+    F, H, W = masks.shape
+    R = H // 8
+    occupied = jnp.any(masks != 0, axis=2)                      # (F, H)
+    n_rows = jnp.sum(occupied, axis=1, dtype=jnp.int32)
+    # the k-th occupied row is the first whose running count reaches k
+    rank = jnp.cumsum(occupied, axis=1, dtype=jnp.int32)
+    rows = jax.vmap(lambda r: jnp.searchsorted(
+        r, jnp.arange(1, R + 1, dtype=jnp.int32)))(rank)
+    rows = jnp.minimum(rows, H - 1).astype(jnp.int32)
+    picked = jnp.take_along_axis(masks, rows[:, :, None], axis=1) != 0
+    picked = jnp.pad(picked, ((0, 0), (0, 0), (0, -W % 8)))
+    bits = picked.reshape(F, R, -1, 8).astype(jnp.uint8)
+    packed = jnp.sum(bits << jnp.arange(8, dtype=jnp.uint8), axis=3,
+                     dtype=jnp.uint8)
+    return rows, n_rows, packed
+
+
 def reduce_frames(frames: np.ndarray, dark: np.ndarray,
                   threshold: float = 200.0, use_kernel: bool = True
                   ) -> List[ReducedFrame]:
@@ -289,10 +331,13 @@ def reduce_frames(frames: np.ndarray, dark: np.ndarray,
     (free while no profiler trace is recording). The copy in and the filter
     wait for the device before their span ends, so that each span holds its
     own work, and the copies' spans carry the bytes they moved as the stat
-    ``bytes``. Each frame's ``hedm.label`` span carries the runs the labeler
-    found (stat ``runs``) and its ``hedm.centroids`` span the signal pixels
-    whose moments it summed (stat ``pixels``): both follow the signal, not
-    the frame size."""
+    ``bytes``. Only the masks' occupied rows come back, bit-packed
+    (:func:`mask_rows`), except for a frame with too many of them, which
+    comes back whole: ``hedm.from_device`` counts ``frames`` and those
+    ``dense_frames``. Each frame's ``hedm.label`` span carries the runs the
+    labeler found (stat ``runs``) and its ``hedm.centroids`` span the
+    signal pixels whose moments it summed (stat ``pixels``): both follow
+    the signal, not the frame size."""
     with TraceAnnotation("hedm.to_device") as span:
         frames_d, dark_d = jnp.asarray(frames), jnp.asarray(dark)
         jax.block_until_ready((frames_d, dark_d))
@@ -305,27 +350,55 @@ def reduce_frames(frames: np.ndarray, dark: np.ndarray,
         else:
             from repro.kernels.hedm_reduce_ref import reference
             masks, counts = reference(frames_d, dark_d, threshold=threshold)
-        jax.block_until_ready((masks, counts))
-    with TraceAnnotation("hedm.from_device") as span:
-        masks = np.asarray(masks)
-        counts = np.asarray(counts)
-        span.set_metadata(bytes=masks.nbytes + counts.nbytes)
+        compact = mask_rows(masks)
+        jax.block_until_ready((masks, counts, compact))
+    back = _copy_back("hedm.from_device", masks, counts, compact)
     # The pixels' (y, x) come from the labeler's runs, so this span holds no
     # work; it stays, once a call, because benchmarks/chip's centroid reader
     # and its tests still count it.
     with TraceAnnotation("hedm.index_grid"):
         pass
-    return [_peak_list(f, masks[f], counts[f], frames[f])
+    return [_peak_list(f, *back[f], frames[f])
             for f in range(frames.shape[0])]
 
 
-def _peak_list(frame_id: int, mask: np.ndarray, count, frame: np.ndarray
-               ) -> ReducedFrame:
+def _copy_back(span_name: str, masks: jax.Array, counts: jax.Array,
+               compact: Tuple[jax.Array, jax.Array, jax.Array], **stats
+               ) -> List[Tuple[np.ndarray, Optional[np.ndarray], int]]:
+    """Copy stage 1's result to the host under span ``span_name``: the
+    counts and :func:`mask_rows`' ``compact`` form in one transfer, then
+    each frame whose occupied rows overflow the compact form whole from
+    the device ``masks``. The span's stats are ``bytes`` (all copied),
+    ``frames``, ``dense_frames`` and those in ``stats``. Returns per frame
+    ``(mask, rows, count)``: the packed occupied rows and their indices,
+    or the dense mask and ``None``."""
+    with TraceAnnotation(span_name) as span:
+        rows, n_rows, packed, counts = jax.device_get((*compact, counts))
+        over = np.flatnonzero(n_rows > rows.shape[1]).tolist()
+        dense = {f: np.asarray(masks[f]) for f in over}
+        span.set_metadata(
+            bytes=sum(a.nbytes for a in (rows, n_rows, packed, counts,
+                                         *dense.values())),
+            frames=len(n_rows), dense_frames=len(dense), **stats)
+    return [(dense[f], None, counts[f]) if f in dense
+            else (packed[f, :n_rows[f]], rows[f, :n_rows[f]], counts[f])
+            for f in range(len(n_rows))]
+
+
+def _peak_list(frame_id: int, mask: np.ndarray, rows: Optional[np.ndarray],
+               count, frame: np.ndarray) -> ReducedFrame:
     """Host half of stage 1 for one frame: label the mask's runs (span
     ``hedm.label``, stat ``runs``) and take each component's centroid
-    from the raw frame (span ``hedm.centroids``, stat ``pixels``)."""
+    from the raw frame (span ``hedm.centroids``, stat ``pixels``).
+    ``mask`` is the dense mask with ``rows`` ``None``, or else the
+    frame's occupied rows ``rows``, bit-packed."""
     with TraceAnnotation("hedm.label") as span:
-        runs = label_runs(mask > 0)
+        if rows is None:
+            runs = label_runs(mask > 0)
+        else:
+            sub = np.unpackbits(mask, axis=1, count=frame.shape[1],
+                                bitorder="little").view(bool)
+            runs = label_runs(sub, rows)
         span.set_metadata(runs=len(runs.row))
     with TraceAnnotation("hedm.centroids") as span:
         peaks = run_centroids(runs, frame)
@@ -414,8 +487,9 @@ def reduce_resident(resident: ResidentScan, window: int = 16,
     other). Frame ids are global, and every frame comes back exactly once.
     Masks are the kernel's and peaks come from the host copy, as in
     :func:`reduce_frames`, under the same ``hedm.filter``, ``hedm.label``
-    and ``hedm.centroids`` spans; the copy back is span
-    ``hedm.from_devices`` (stats ``bytes`` and ``chips``)."""
+    and ``hedm.centroids`` spans, and come back in the same compact form;
+    the copy back is span ``hedm.from_devices`` (stats ``bytes``,
+    ``frames``, ``dense_frames`` and ``chips``)."""
     chips = resident.mesh.shape[resident.axis]
     share = len(resident.host) // chips
     for first in range(0, share, window):
@@ -423,17 +497,14 @@ def reduce_resident(resident: ResidentScan, window: int = 16,
         step = _own_frames_filter(resident.mesh, resident.axis, width,
                                   float(threshold))
         with TraceAnnotation("hedm.filter"):
-            masks, counts = step(resident.frames, resident.dark,
-                                 np.int32(first))
-            jax.block_until_ready((masks, counts))
-        with TraceAnnotation("hedm.from_devices") as span:
-            masks = np.asarray(masks)
-            counts = np.asarray(counts)
-            span.set_metadata(bytes=masks.nbytes + counts.nbytes,
-                              chips=chips)
+            masks, counts, *compact = step(resident.frames, resident.dark,
+                                           np.int32(first))
+            jax.block_until_ready((masks, counts, compact))
+        back = _copy_back("hedm.from_devices", masks, counts, compact,
+                          chips=chips)
         ids = [i * share + first + j for i in range(chips)
                for j in range(width)]
-        yield [_peak_list(f, masks[k], counts[k], resident.host[f])
+        yield [_peak_list(f, *back[k], resident.host[f])
                for k, f in enumerate(ids)]
 
 
@@ -441,18 +512,20 @@ def reduce_resident(resident: ResidentScan, window: int = 16,
 def _own_frames_filter(mesh: Mesh, axis: str, window: int, threshold: float):
     """The jitted step of :func:`reduce_resident`: on every chip, frames
     ``[i*F/P + first, ... + window)`` of its replica through
-    ``hedm_reduce``; masks and counts come out stacked chip by chip."""
+    ``hedm_reduce`` and :func:`mask_rows`; masks, counts and the compact
+    rows come out stacked chip by chip, each chip's from its own frames."""
     from repro.kernels.ops import hedm_reduce
     chips = mesh.shape[axis]
 
     def filter_own_frames(frames, dark, first):
         start = jax.lax.axis_index(axis) * (frames.shape[0] // chips) + first
         part = jax.lax.dynamic_slice_in_dim(frames, start, window)
-        return hedm_reduce(part, dark, threshold=threshold)
+        masks, counts = hedm_reduce(part, dark, threshold=threshold)
+        return (masks, counts, *mask_rows(masks))
 
     return jax.jit(jax.shard_map(
         filter_own_frames, mesh=mesh, in_specs=(P(), P(), P()),
-        out_specs=(P(axis), P(axis)), check_vma=False))
+        out_specs=(P(axis),) * 5, check_vma=False))
 
 
 @dataclass

@@ -117,7 +117,25 @@ def test_resident_step_compiles_for_v5e_2x2_at_detector_size(
             jax.ShapeDtypeStruct((), jnp.int32, sharding=whole))
     step = pipeline._own_frames_filter(four_chips, "data", window, 200.0)
     compiled = step.lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    # each chip returns its own window's uint8 masks (and padded counts)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # each chip compacts its own frames: nothing crosses the interconnect
+    assert not any(op in text for op in ("all-gather", "all-to-all",
+                                         "all-reduce", "collective-permute"))
+    # each chip returns its own window's uint8 masks (and padded counts,
+    # and the compact rows, under a quarter of a frame in all)
     out = compiled.memory_analysis().output_size_in_bytes
     assert out // (FRAME * FRAME) == window
+
+
+@pytest.mark.parametrize("window", [16, 8])
+def test_mask_rows_compiles_for_v5e_at_detector_size(one_chip, window):
+    """The compaction of a window's masks: an eighth of the rows, 8 pixels
+    a byte, with their indices (tiles pad the counts): about a 64th of the
+    masks' bytes out."""
+    x = jax.ShapeDtypeStruct((window, FRAME, FRAME), jnp.uint8,
+                             sharding=one_chip)
+    mem = pipeline.mask_rows.lower(x).compile().memory_analysis()
+    dense = window * FRAME * FRAME
+    assert dense // 64 < mem.output_size_in_bytes < dense // 62
+    assert mem.temp_size_in_bytes <= dense // 8

@@ -5,9 +5,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.hedm.pipeline import (fit_grid, label_components, label_runs,
-                                 make_gvectors, reduce_frames, run_centroids,
-                                 simulate_detector_frames, stream_to_fs,
-                                 synth_grid_observations, _union_find_label)
+                                 make_gvectors, mask_rows, reduce_frames,
+                                 run_centroids, simulate_detector_frames,
+                                 stream_to_fs, synth_grid_observations,
+                                 _union_find_label)
 from repro.core.fabric import Fabric
 from repro.kernels.hedm_reduce_ref import reference
 
@@ -134,13 +135,27 @@ def _mask(case):
         m[0, :] = m[2, :] = m[4, :] = True
         m[1, 0] = m[3, 4] = True
         return m
+    # labels that hinge on which rows are adjacent in the frame
+    m = np.zeros((12, 10), bool)
+    if case == "split_by_an_empty_row":     # one column, broken at row 4
+        m[2:4, 3] = m[5:8, 3] = True
+        m[9, :4] = m[11, 2:6] = True
+        return m
+    if case == "first_and_last_row":
+        m[0, 1:4] = m[1, 3] = m[-1, 5:] = m[-2, 9] = True
+        return m
+    if case == "full_row":
+        m[6, :] = True
+        m[5, 0] = m[7, 9] = m[9, 4] = True
+        return m
     rng = np.random.default_rng(int(case.split("_")[1]))
     H, W = (int(v) for v in rng.integers(1, 48, 2))
     return rng.random((H, W)) < rng.uniform(0.05, 0.8)
 
 
 MASKS = ["empty", "full", "edge_columns", "corner", "single_pixels",
-         "snake"] + [f"random_{i}" for i in range(6)]
+         "snake", "split_by_an_empty_row", "first_and_last_row",
+         "full_row"] + [f"random_{i}" for i in range(6)]
 
 
 @pytest.mark.parametrize("case", MASKS)
@@ -188,6 +203,77 @@ def test_reduce_frames_peaks_equal_the_dense_reference(dtype):
         labels, n = _union_find_label(mask > 0)
         assert r.n_spots == n
         assert np.array_equal(r.peaks, _dense_peaks(labels, n, frame))
+
+
+@pytest.mark.parametrize("case", MASKS)
+def test_label_runs_of_the_occupied_rows_equal_the_whole_frame(case):
+    mask = _mask(case)
+    rows = np.flatnonzero(mask.any(axis=1))
+    whole, part = label_runs(mask), label_runs(mask[rows], rows)
+    assert part.n == whole.n
+    for got, want in zip(part[:4], whole[:4]):
+        assert np.array_equal(got, want)
+    if case == "split_by_an_empty_row":
+        assert whole.n == 4               # the broken column is two spots
+
+
+def _compact_reference(mask):
+    """``mask_rows`` of one frame in numpy: ``any``, ``flatnonzero`` and
+    ``packbits``."""
+    H, W = mask.shape
+    occupied = np.flatnonzero(mask.any(axis=1))
+    keep = occupied[:H // 8]
+    return keep, len(occupied), np.packbits(mask[keep], axis=1,
+                                            bitorder="little")
+
+
+def _frame_with_rows(n, H=64, W=64, seed=0):
+    rng = np.random.default_rng(seed)
+    m = np.zeros((H, W), np.uint8)
+    for r in rng.choice(H, n, replace=False):
+        m[r, rng.choice(W, rng.integers(1, W + 1), replace=False)] = 1
+    return m
+
+
+@pytest.mark.parametrize("occupied,W", [(0, 64), (3, 64), (8, 64), (9, 64),
+                                        (40, 64), (64, 64), (5, 60)])
+def test_mask_rows_equal_numpy_any_flatnonzero_and_packbits(occupied, W):
+    """64 rows give a capacity of 8: a frame exactly at it, one row over
+    it, a full frame, an empty one, and a width off the byte."""
+    masks = np.stack([_frame_with_rows(occupied, W=W, seed=s)
+                      for s in range(3)])
+    rows, n_rows, packed = (np.asarray(a) for a in mask_rows(masks))
+    assert rows.shape == (3, 8) and rows.dtype == np.int32
+    assert packed.shape == (3, 8, -(-W // 8)) and packed.dtype == np.uint8
+    for f, mask in enumerate(masks):
+        keep, n, bits = _compact_reference(mask > 0)
+        k = len(keep)
+        assert n_rows[f] == n == occupied
+        assert np.array_equal(rows[f, :k], keep)
+        assert np.array_equal(packed[f, :k], bits)
+        assert np.array_equal(np.unpackbits(packed[f, :k], axis=1, count=W,
+                                            bitorder="little"), mask[keep])
+
+
+@pytest.mark.parametrize("size,spots,seed,use_kernel", [
+    (64, 1, 5, False), (64, 4, 5, False), (96, 6, 11, True),
+    (128, 2, 3, False), (256, 6, 3, False)])
+def test_reduce_frames_equals_the_dense_path(size, spots, seed, use_kernel):
+    """Row-sparse copies back give exactly the peaks of labeling every
+    frame's whole mask; the scans take the compact path, the overflow one,
+    or both."""
+    frames, dark = simulate_detector_frames(4, size=size, n_spots=spots,
+                                            seed=seed)
+    frames, dark = frames.astype(np.uint16), dark.astype(np.uint16)
+    red = reduce_frames(frames, dark, threshold=200.0, use_kernel=use_kernel)
+    masks, counts = reference(jnp.asarray(frames), jnp.asarray(dark),
+                              threshold=200.0)
+    for r, frame, mask, count in zip(red, frames, np.asarray(masks),
+                                     np.asarray(counts)):
+        runs = label_runs(mask > 0)
+        assert (r.n_signal_pixels, r.n_spots) == (int(count), runs.n)
+        assert np.array_equal(r.peaks, run_centroids(runs, frame))
+    assert sum(r.n_spots for r in red) > 0
 
 
 def test_detector_sim_spots_are_gaussian_and_bright():

@@ -2,7 +2,9 @@
 
 ``reduce_frames`` marks its copies, filter, labeling, index grid and
 centroids with ``hedm.*`` spans on the JAX profiler's host plane, and its
-copies' spans carry the bytes they moved as the stat ``bytes``; each frame's
+copies' spans carry the bytes they moved as the stat ``bytes`` (the copy
+back also the frames it brought and how many came whole, ``frames`` and
+``dense_frames``); each frame's
 labeling and centroid spans carry the runs and signal pixels they handled
 (stats ``runs`` and ``pixels``). The spans cost nothing while no trace
 records, and outputs are the same with a trace recording or not.
@@ -14,7 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.profiler import ProfileData, TraceAnnotation
 
-from repro.hedm.pipeline import (reduce_frames, reduce_frames_online,
+from repro.hedm.pipeline import (label_runs, reduce_frames,
+                                 reduce_frames_online, run_centroids,
                                  simulate_detector_frames, _union_find_label)
 from repro.kernels.hedm_reduce_ref import reference
 
@@ -93,6 +96,21 @@ def test_trace_holds_every_span_inside_the_caller(scan, tmp_path,
     assert names[5:] == ["hedm.label", "hedm.centroids"] * F
 
 
+def _occupied_rows(frames, dark):
+    """Rows of each frame's reference mask that hold any signal."""
+    masks, _ = reference(jnp.asarray(frames), jnp.asarray(dark),
+                         threshold=200.0)
+    return [int(m.any(axis=1).sum()) for m in np.asarray(masks)]
+
+
+def _compact_bytes(n_frames):
+    """A frame's compact copy back at SIZE x SIZE: SIZE // 8 int32 row
+    indices, an int32 row count, SIZE // 8 rows bit-packed, an int32
+    signal count."""
+    rows = SIZE // 8
+    return n_frames * (rows * 4 + 4 + rows * SIZE // 8 + 4)
+
+
 @pytest.mark.parametrize("use_kernel,dtype,stored", [
     (False, np.uint16, np.uint16), (True, np.uint16, np.uint16),
     # without x64 a float64 stack lands on the device as float32
@@ -103,11 +121,40 @@ def test_copy_spans_carry_the_bytes_moved(scan, tmp_path, use_kernel, dtype,
     _, events = _traced(
         tmp_path, lambda: reduce_frames(frames, dark, use_kernel=use_kernel))
     moved = {n: st["bytes"] for n, _, _, st in events if "bytes" in st}
-    # frames and dark frame in; a uint8 mask a pixel and an int32 count a
-    # frame back
+    back, = [st for n, _, _, st in events if n == "hedm.from_device"]
+    dense = sum(n > SIZE // 8 for n in _occupied_rows(*scan))
+    # frames and dark frame in; back, every frame's occupied rows, compact,
+    # and a uint8 mask a pixel for each frame with more of them than fit
     assert moved == {
         "hedm.to_device": (F + 1) * PIXELS * np.dtype(stored).itemsize,
-        "hedm.from_device": F * PIXELS + F * 4}
+        "hedm.from_device": _compact_bytes(F) + dense * PIXELS}
+    assert (back["frames"], back["dense_frames"]) == (F, dense)
+
+
+def test_a_frame_whose_rows_overflow_comes_back_whole(tmp_path):
+    """64 rows leave room for 8 in the compact copy: a frame of one spot
+    fits, one of four spots does not and is copied whole; both label as
+    their whole masks do."""
+    sparse, dark = simulate_detector_frames(1, size=SIZE, n_spots=1, seed=5)
+    busy, _ = simulate_detector_frames(1, size=SIZE, n_spots=4, seed=5)
+    frames = np.concatenate([sparse, busy]).astype(np.uint16)
+    dark = dark.astype(np.uint16)
+    occupied = _occupied_rows(frames, dark)
+    assert occupied[0] <= SIZE // 8 < occupied[1]
+    got, events = _traced(tmp_path,
+                          lambda: reduce_frames(frames, dark, use_kernel=False))
+    back, = [st for n, _, _, st in events if n == "hedm.from_device"]
+    assert (back["frames"], back["dense_frames"]) == (2, 1)
+    assert back["bytes"] == _compact_bytes(2) + PIXELS
+    masks, counts = reference(jnp.asarray(frames), jnp.asarray(dark),
+                              threshold=200.0)
+    for r, frame, mask, count in zip(got, frames, np.asarray(masks),
+                                     np.asarray(counts)):
+        _, n = _union_find_label(mask > 0)
+        assert (r.n_signal_pixels, r.n_spots) == (int(count), n)
+        assert np.array_equal(r.peaks, run_centroids(label_runs(mask > 0),
+                                                     frame))
+        assert n > 0
 
 
 def test_online_reduction_has_one_set_of_spans_a_window(scan, tmp_path):

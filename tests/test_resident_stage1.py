@@ -6,7 +6,9 @@ share of frames from its replica, a window at a time, and labels on the
 host. Everything runs in one subprocess with four virtual CPU devices (the
 main pytest process keeps its single device); the tests read what it
 found. 40 frames over 4 chips is 10 a chip, so a window of 4 leaves a
-ragged last step of 2.
+ragged last step of 2. At 64x64 a frame's occupied rows come back compact
+only up to 8 of them: most frames of six spots overflow and come back
+whole, and a second scan of one spot a frame takes the compact path.
 """
 import json
 import os
@@ -26,6 +28,7 @@ SCRIPT = textwrap.dedent(f"""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from repro.core.staging import device_replicate
     from repro.hedm import pipeline as hp
+    from repro.kernels.hedm_reduce_ref import reference
 
     frames, dark = hp.simulate_detector_frames({FRAMES}, size={SIZE},
                                                n_spots=6, seed=11)
@@ -48,6 +51,27 @@ SCRIPT = textwrap.dedent(f"""
     out["steps"] = steps
     out["online"] = rows(r for c in hp.reduce_frames_online(
         scan, dark, window={WINDOW}, threshold=200) for r in c)
+
+    def dense(scan):      # every frame labeled from its whole mask
+        masks, counts = reference(jax.numpy.asarray(scan),
+                                  jax.numpy.asarray(dark), threshold=200.0)
+        found = []
+        for f, (m, c) in enumerate(zip(np.asarray(masks),
+                                       np.asarray(counts))):
+            runs = hp.label_runs(m > 0)
+            found.append(hp.ReducedFrame(f, int(c), runs.n,
+                                         hp.run_centroids(runs, scan[f])))
+        return rows(found), [int(m.any(axis=1).sum())
+                             for m in np.asarray(masks)]
+
+    out["dense"], out["occupied"] = dense(scan)
+    sparse = np.clip(np.rint(hp.simulate_detector_frames(
+        {FRAMES}, size={SIZE}, n_spots=1, seed=11)[0]), 0, 65535
+        ).astype(np.uint16)
+    out["sparse_resident"] = rows(r for step in hp.reduce_resident(
+        hp.stage_scan(mesh, sparse, dark), window={WINDOW}, threshold=200)
+        for r in step)
+    out["sparse_dense"], out["sparse_occupied"] = dense(sparse)
     out["replicas"] = sorted(
         [s.device.id, bool(np.array_equal(np.asarray(s.data), scan)),
          bool(np.array_equal(np.asarray(s2.data), dark))]
@@ -105,6 +129,19 @@ def test_resident_path_equals_the_online_path_bit_for_bit(found):
     assert sum(r[2] for r in got) > 0
 
 
+def test_resident_path_equals_labeling_whole_masks_bit_for_bit(found):
+    """Both scans, the one whose frames mostly come back whole and the one
+    whose frames all come back compact, give the peaks of labeling every
+    frame's whole mask."""
+    got = sorted((r for step in found["steps"] for r in step),
+                 key=lambda r: r[0])
+    assert got == found["dense"]
+    assert sorted(found["sparse_resident"]) == found["sparse_dense"]
+    assert max(found["sparse_occupied"]) <= SIZE // 8
+    assert sum(n > SIZE // 8 for n in found["occupied"]) > FRAMES // 2
+    assert sum(r[2] for r in found["sparse_dense"]) >= FRAMES
+
+
 def test_every_frame_comes_back_once_with_global_ids_and_a_ragged_step(
         found):
     share = FRAMES // CHIPS
@@ -145,7 +182,14 @@ def test_staging_and_copy_back_spans_carry_their_bytes(found):
     assert spans["hedm.all_gather"] == [{"bytes": (CHIPS - 1) * scan_b}]
     back = spans["hedm.from_devices"]
     assert len(back) == len(found["steps"])
-    per_frame = SIZE * SIZE + 4                  # uint8 mask, int32 count
-    assert [s["bytes"] for s in back] == [len(step) * per_frame
-                                          for step in found["steps"]]
+    rows = SIZE // 8
+    # a frame's int32 row indices, row count and signal count, and its
+    # occupied rows bit-packed; a uint8 mask a pixel for each frame whose
+    # rows overflow
+    compact = rows * 4 + 4 + rows * SIZE // 8 + 4
+    for s, step in zip(back, found["steps"]):
+        whole = sum(found["occupied"][r[0]] > rows for r in step)
+        assert (s["frames"], s["dense_frames"]) == (len(step), whole)
+        assert s["bytes"] == len(step) * compact + whole * SIZE * SIZE
+    assert sum(s["dense_frames"] for s in back) > 0
     assert all(s["chips"] == CHIPS for s in back)
