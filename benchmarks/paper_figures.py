@@ -3,12 +3,11 @@
 Each function returns a list of (name, us_per_call, derived) rows;
 benchmarks/run.py prints them as CSV. Simulated-fabric times use the
 BGQ-calibrated constants (repro.core.fabric — fit to the paper's measured
-aggregates); kernel benches measure real wall time on this host.
+aggregates). Chip timings come from benchmarks/chip, not from here.
 """
 from __future__ import annotations
 
 import random
-import time
 from typing import List, Tuple
 
 import numpy as np
@@ -88,26 +87,6 @@ def fig13_ff_stage2_makespan() -> List[Row]:
     return _makespan_rows("fig13_ff2", 4109, (5, 25), (40, 80, 160, 320))
 
 
-def nf_reduction() -> List[Row]:
-    """§VI-A: NF data reduction — measured kernel throughput on this host,
-    scaled to the paper's 736-image workload."""
-    import jax.numpy as jnp
-    from repro.hedm.pipeline import simulate_detector_frames
-    from repro.kernels.ops import hedm_reduce
-    frames, dark = simulate_detector_frames(8, size=256, n_spots=8)
-    fj, dj = jnp.asarray(frames), jnp.asarray(dark)
-    hedm_reduce(fj, dj)                      # compile
-    t0 = time.perf_counter()
-    masks, counts = hedm_reduce(fj, dj)
-    masks.block_until_ready()
-    dt = time.perf_counter() - t0
-    per_frame = dt / 8
-    return [("nf_reduction_per_frame", per_frame * 1e6,
-             f"px_per_s={256*256/per_frame:.2e}"),
-            ("nf_reduction_736_frames_est", per_frame * 736 * 1e6,
-             "paper=106s_on_320_cores")]
-
-
 def metadata_contention() -> List[Row]:
     """§IV: leader-glob + broadcast vs per-rank glob storm."""
     from repro.core.fabric import BGQ, Fabric
@@ -145,26 +124,6 @@ def checkpoint_staged_restore() -> List[Row]:
     return rows
 
 
-def kernel_microbench() -> List[Row]:
-    """Wall-time micro-benchmarks of the Pallas kernels (interpret mode on
-    CPU: correctness-path timing, NOT TPU perf — the roofline report covers
-    the TPU-side projections)."""
-    import jax
-    import jax.numpy as jnp
-    from repro.kernels.ops import flash_attention
-    key = jax.random.PRNGKey(0)
-    q = jax.random.normal(key, (1, 512, 8, 64), jnp.float32)
-    k = jax.random.normal(key, (1, 512, 4, 64), jnp.float32)
-    v = jax.random.normal(key, (1, 512, 4, 64), jnp.float32)
-    flash_attention(q, k, v).block_until_ready()
-    t0 = time.perf_counter()
-    flash_attention(q, k, v).block_until_ready()
-    dt = time.perf_counter() - t0
-    flops = 4 * 512 * 512 * 8 * 64
-    return [("flash_attention_512_interp", dt * 1e6,
-             f"gflops={flops/dt/1e9:.2f}")]
-
-
 ALL = [fig10_staging_write, fig11_end_to_end, fig12_ff_stage1_makespan,
-       fig13_ff_stage2_makespan, nf_reduction, metadata_contention,
-       checkpoint_staged_restore, kernel_microbench]
+       fig13_ff_stage2_makespan, metadata_contention,
+       checkpoint_staged_restore]

@@ -1,9 +1,7 @@
 """Benchmark harness: one function per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV on stdout (harness contract). The
-roofline table (EXPERIMENTS.md §Roofline) is produced separately by
-``python -m benchmarks.roofline`` from the dry-run artifacts; the
-staging/labeling hot-path microbenchmark by ``--staging``, the
+staging/labeling hot-path microbenchmark is produced by ``--staging``, the
 batch-vs-streaming turnaround comparison by ``--streaming``, and the
 multi-tenant staging-service scenario by ``--service``, the
 fault-tolerance repair-vs-restage comparison by ``--faults``, the

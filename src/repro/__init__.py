@@ -7,18 +7,12 @@ Reproduction and beyond-paper extension of:
 Layers:
   repro.core         -- staging, I/O hook, leader groups, node-local cache,
                         many-task executor, dataflow futures (the paper).
-  repro.models       -- pure-JAX model zoo (10 assigned architectures).
-  repro.kernels      -- Pallas TPU kernels (flash attention, SSD scan, WKV6,
-                        HEDM stage-1 reduction) + jnp oracles.
+  repro.kernels      -- Pallas TPU kernel for HEDM stage-1 reduction + its
+                        jnp oracle.
   repro.data         -- staged input pipeline + detector-stream simulator.
-  repro.train        -- optimizer, train_step, grad compression.
-  repro.serve        -- KV-cache serving, prefill/decode, continuous batching.
-  repro.distributed  -- mesh + sharding rules (FSDP x TP x EP x SP).
   repro.checkpoint   -- sharded checkpoints w/ collective-staged restore.
   repro.runtime      -- fault tolerance, elastic rescale, restart driver.
   repro.hedm         -- the paper's application (NF/FF-HEDM stages).
-  repro.configs      -- assigned architecture configs + shapes.
-  repro.launch       -- mesh/dryrun/train/serve entry points.
 """
 
 __version__ = "1.0.0"

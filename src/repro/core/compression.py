@@ -18,9 +18,7 @@ bytes are separable in every report.
 The identity codec (``"none"``) resolves to ``None`` everywhere, which
 keeps every pre-existing code path bit-exact — the regression anchor.
 
-This is unrelated to :mod:`repro.train.compression` (int8 gradient
-quantization for the training loop); this module is about staging
-wire-byte reduction.
+This module is about staging wire-byte reduction.
 """
 from __future__ import annotations
 

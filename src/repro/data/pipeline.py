@@ -7,7 +7,7 @@ A dataset lives on the shared FS as shard files. Per training wave:
   * hosts cut batches from node-local data at RAM speed; repeats (multiple
     epochs / eval reuse) hit the pinned cache at zero FS cost (§VI-B).
 
-`StagedLoader.batches()` yields jnp batches for train_step; the simulated-
+`StagedLoader.batches()` yields jnp batches for a training step; the simulated-
 time accounting (stage vs naive) feeds the benchmarks.
 """
 from __future__ import annotations

@@ -1,46 +1,15 @@
-"""Public jit'd wrappers for the Pallas kernels.
+"""Public jit'd wrapper for the Pallas stage-1 kernel.
 
-Each op auto-selects interpret mode off-TPU (the CPU container) and the
-compiled Mosaic path on TPU. The XLA reference implementations in
-repro.models remain the dry-run/AOT path (Pallas does not lower on the CPU
-backend); these wrappers are the deployment path and the test subject.
+The kernel auto-selects interpret mode off-TPU (on the CPU) and the
+compiled Mosaic path on TPU; its jnp oracle is ``hedm_reduce_ref``.
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
 
-from repro.kernels import flash_attention as _fa
 from repro.kernels import hedm_reduce as _hr
-from repro.kernels import mamba2_scan as _ms
-from repro.kernels import rwkv6_wkv as _rw
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "window", "scale",
-                                             "block_q", "block_k"))
-def flash_attention(q, k, v, *, causal=True, window=0, scale=None,
-                    block_q=128, block_k=128):
-    return _fa.flash_attention(q, k, v, causal=causal, window=window,
-                               scale=scale, block_q=block_q, block_k=block_k,
-                               interpret=not _on_tpu())
-
-
-@functools.partial(jax.jit, static_argnames=("chunk",))
-def mamba2_scan(x, dt, A, Bm, Cm, chunk=128):
-    return _ms.mamba2_scan(x, dt, A, Bm, Cm, chunk=chunk,
-                           interpret=not _on_tpu())
-
-
-@functools.partial(jax.jit, static_argnames=("chunk",))
-def rwkv6_wkv(r, k, v, w, u, chunk=32):
-    return _rw.rwkv6_wkv(r, k, v, w, u, chunk=chunk,
-                         interpret=not _on_tpu())
 
 
 @functools.partial(jax.jit, static_argnames=("threshold",))

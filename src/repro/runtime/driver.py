@@ -76,6 +76,7 @@ class TrainDriver:
                 # node loss mid-step: restart from last complete checkpoint
                 self.report.restarts += 1
                 del self.failure_schedule[step]
+                self.store.wait()
                 latest = self.store.latest_step() or 0
                 step_fn, state = self.build_step(mesh_spec)
                 if self.store.latest_step() is not None:

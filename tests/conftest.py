@@ -1,12 +1,28 @@
 import os
+import subprocess
 import sys
 
 import numpy as np
 import pytest
 
-# Tests run on the default single CPU device (the dry-run alone uses 512
-# placeholder devices — set ONLY inside launch/dryrun.py, never globally).
+# Tests run on the default single CPU device; a test that needs a mesh
+# starts a subprocess with virtual devices (`run_with_devices`).
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+
+
+def run_with_devices(code: str, n: int) -> str:
+    """Run ``code`` in a Python subprocess that sees ``n`` virtual CPU
+    devices (the pytest process keeps its one device); returns its
+    standard output and fails the test if it exits non-zero."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    return out.stdout
 
 
 # ---------------------------------------------------------------------------

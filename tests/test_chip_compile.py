@@ -103,6 +103,18 @@ def test_scan_all_gather_compiles_for_v5e_2x2_without_a_second_replica(
     assert mem.temp_size_in_bytes < replica // 4
 
 
+def _compile_resident_step(mesh, window):
+    """``reduce_resident``'s step over the whole scan resident on every
+    chip of ``mesh``."""
+    whole = NamedSharding(mesh, P())
+    args = (jax.ShapeDtypeStruct((SCAN_FRAMES, FRAME, FRAME), jnp.uint16,
+                                 sharding=whole),
+            jax.ShapeDtypeStruct((FRAME, FRAME), jnp.uint16, sharding=whole),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=whole))
+    step = pipeline._own_frames_filter(mesh, "data", window, 200.0)
+    return step.lower(*args).compile()
+
+
 @pytest.mark.parametrize("window", [16, 8])
 def test_resident_step_compiles_for_v5e_2x2_at_detector_size(
         four_chips, monkeypatch, window):
@@ -110,13 +122,7 @@ def test_resident_step_compiles_for_v5e_2x2_at_detector_size(
     step and the ragged 8-frame tail of a 184-frame share."""
     # off the chip the kernel would pick the interpreter
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    whole = NamedSharding(four_chips, P())
-    args = (jax.ShapeDtypeStruct((SCAN_FRAMES, FRAME, FRAME), jnp.uint16,
-                                 sharding=whole),
-            jax.ShapeDtypeStruct((FRAME, FRAME), jnp.uint16, sharding=whole),
-            jax.ShapeDtypeStruct((), jnp.int32, sharding=whole))
-    step = pipeline._own_frames_filter(four_chips, "data", window, 200.0)
-    compiled = step.lower(*args).compile()
+    compiled = _compile_resident_step(four_chips, window)
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     # each chip compacts its own frames: nothing crosses the interconnect
@@ -124,6 +130,19 @@ def test_resident_step_compiles_for_v5e_2x2_at_detector_size(
                                          "all-reduce", "collective-permute"))
     # each chip returns its own window's uint8 masks (and padded counts,
     # and the compact rows, under a quarter of a frame in all)
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert out // (FRAME * FRAME) == window
+
+
+@pytest.mark.parametrize("window", [16, 8])
+def test_resident_step_compiles_for_one_v5e_at_detector_size(
+        topo, monkeypatch, window):
+    """``reduce_resident``'s step on a one-chip mesh, the scan resident
+    whole in its HBM: the 16-frame step and a ragged 8-frame one."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _compile_resident_step(
+        Mesh(np.array(topo.devices[:1]), ("data",)), window)
+    assert "tpu_custom_call" in compiled.as_text()
     out = compiled.memory_analysis().output_size_in_bytes
     assert out // (FRAME * FRAME) == window
 

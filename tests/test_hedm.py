@@ -6,9 +6,9 @@ import jax.numpy as jnp
 
 from repro.hedm.pipeline import (fit_grid, label_components, label_runs,
                                  make_gvectors, mask_rows, reduce_frames,
-                                 run_centroids, simulate_detector_frames,
-                                 stream_to_fs, synth_grid_observations,
-                                 _union_find_label)
+                                 reduce_frames_online, run_centroids,
+                                 simulate_detector_frames, stream_to_fs,
+                                 synth_grid_observations, _union_find_label)
 from repro.core.fabric import Fabric
 from repro.kernels.hedm_reduce_ref import reference
 
@@ -257,7 +257,10 @@ def test_mask_rows_equal_numpy_any_flatnonzero_and_packbits(occupied, W):
 
 @pytest.mark.parametrize("size,spots,seed,use_kernel", [
     (64, 1, 5, False), (64, 4, 5, False), (96, 6, 11, True),
-    (128, 2, 3, False), (256, 6, 3, False)])
+    (128, 2, 3, False), (256, 6, 3, False),
+    # widths off the byte and off the lanes
+    (60, 1, 5, False), (60, 3, 5, False), (130, 1, 5, True),
+    (130, 3, 5, True)])
 def test_reduce_frames_equals_the_dense_path(size, spots, seed, use_kernel):
     """Row-sparse copies back give exactly the peaks of labeling every
     frame's whole mask; the scans take the compact path, the overflow one,
@@ -274,6 +277,32 @@ def test_reduce_frames_equals_the_dense_path(size, spots, seed, use_kernel):
         assert (r.n_signal_pixels, r.n_spots) == (int(count), runs.n)
         assert np.array_equal(r.peaks, run_centroids(runs, frame))
     assert sum(r.n_spots for r in red) > 0
+
+
+ONLINE_FRAMES = 7
+
+
+@pytest.mark.parametrize("window", [1, 3, ONLINE_FRAMES, ONLINE_FRAMES + 5])
+def test_reduce_frames_online_equals_one_reduce_frames_call(window):
+    """Windows of one frame, a ragged last window, one window of the whole
+    stack and one longer than it: the same frames as one call, with
+    global frame ids."""
+    frames, dark = simulate_detector_frames(ONLINE_FRAMES, size=64,
+                                            n_spots=3, seed=8)
+    frames, dark = frames.astype(np.uint16), dark.astype(np.uint16)
+    chunks = list(reduce_frames_online(frames, dark, window=window,
+                                       threshold=200.0, use_kernel=False))
+    assert [len(c) for c in chunks] == [
+        min(window, ONLINE_FRAMES - w0)
+        for w0 in range(0, ONLINE_FRAMES, window)]
+    got = [(r.frame_id, r.n_signal_pixels, r.n_spots, r.peaks.tobytes())
+           for c in chunks for r in c]
+    want = [(r.frame_id, r.n_signal_pixels, r.n_spots, r.peaks.tobytes())
+            for r in reduce_frames(frames, dark, threshold=200.0,
+                                   use_kernel=False)]
+    assert got == want
+    assert [g[0] for g in got] == list(range(ONLINE_FRAMES))
+    assert sum(g[2] for g in got) > 0
 
 
 def test_detector_sim_spots_are_gaussian_and_bright():

@@ -11,14 +11,11 @@ only up to 8 of them: most frames of six spots overflow and come back
 whole, and a second scan of one spot a frame takes the compact path.
 """
 import json
-import os
-import subprocess
-import sys
 import textwrap
 
 import pytest
+from conftest import run_with_devices
 
-ROOT = os.path.join(os.path.dirname(__file__), "..")
 CHIPS, FRAMES, SIZE, WINDOW = 4, 40, 64, 4
 
 SCRIPT = textwrap.dedent(f"""
@@ -110,15 +107,8 @@ SCRIPT = textwrap.dedent(f"""
 
 @pytest.fixture(scope="module")
 def found():
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={CHIPS}"
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
-    p = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
-                       capture_output=True, text=True, timeout=300)
-    assert p.returncode == 0, p.stderr[-4000:]
-    line, = [ln for ln in p.stdout.splitlines() if ln.startswith("RESULT ")]
+    out = run_with_devices(SCRIPT, CHIPS)
+    line, = [ln for ln in out.splitlines() if ln.startswith("RESULT ")]
     return json.loads(line[len("RESULT "):])
 
 
